@@ -50,7 +50,8 @@ def main():
         print(f"\nthreshold: inline={eng.stats.inline} "
               f"offloaded={eng.stats.offloaded} (paper Table III 'Data Size')")
 
-    # 4. tier-3: the DSA-analogue Pallas kernel (interpret mode on CPU)
+    # 4. tier-3: the DSA-analogue Pallas kernel, in the Pallas interpreter
+    # so the example runs on any backend
     x = jax.random.normal(jax.random.key(0), (1024, 256))
     print("\ntier-3 offload_copy kernel (mode x injection):")
     for mode in ("sync", "async", "pipelined"):
@@ -59,7 +60,7 @@ def main():
                                 offload_threshold_bytes=1,
                                 cache_injection=inject)
             y, total = ops.offload_copy(x, scale=2.0, policy=pol,
-                                        inject=inject)
+                                        inject=inject, interpret=True)
             yr, tr = ref.offload_copy(x, scale=2.0, inject=inject)
             ok = bool(jnp.allclose(y, yr, atol=1e-5))
             extra = f" fused_sum={float(total):.1f}" if inject else ""

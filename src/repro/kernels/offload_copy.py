@@ -24,9 +24,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 LANE = 128          # TPU lane width; last dim of blocks
+# Bytes of VMEM scratch the in/out rotation may take: half of v5e's 16 MiB
+# default scoped-VMEM limit, leaving the rest to the compiler.
+VMEM_BUDGET = 8 << 20
 
 
 def _copy_kernel(x_hbm, y_hbm, sum_out, vmem_in, vmem_out, sem_in, sem_out,
@@ -49,7 +50,7 @@ def _copy_kernel(x_hbm, y_hbm, sum_out, vmem_in, vmem_out, sem_in, sem_out,
     @pl.when(i == 0)
     def _():
         if inject:
-            acc[...] = jnp.zeros_like(acc)
+            acc[0, 0] = 0.0
         for d in range(depth):
 
             @pl.when(d < n_blocks)
@@ -93,14 +94,21 @@ def _copy_kernel(x_hbm, y_hbm, sum_out, vmem_in, vmem_out, sem_in, sem_out,
 def offload_copy_pallas(x, *, scale: float = 1.0, out_dtype=None,
                         depth: int = 2, block_rows: int = 256,
                         inject: bool = False, interpret: bool = False):
-    """x: (R, LANE·k) — streams row-blocks through VMEM. Returns (y, sum|None)."""
+    """x: (R, LANE·k) — streams row-blocks through VMEM. Returns (y, sum|None).
+
+    ``block_rows`` is halved until the ``depth``-deep in/out buffers fit
+    :data:`VMEM_BUDGET`, so a wide slab never asks for more VMEM than the
+    chip gives a kernel."""
     assert x.ndim == 2, "offload_copy operates on 2D row-major slabs"
     rows, cols = x.shape
+    out_dtype = jnp.dtype(out_dtype or x.dtype)
     block_rows = min(block_rows, rows)
     assert rows % block_rows == 0, (rows, block_rows)
+    row_bytes = depth * cols * (x.dtype.itemsize + out_dtype.itemsize)
+    while block_rows * row_bytes > VMEM_BUDGET and block_rows % 16 == 0:
+        block_rows //= 2
     n_blocks = rows // block_rows
     depth = max(1, min(depth, n_blocks))
-    out_dtype = jnp.dtype(out_dtype or x.dtype)
 
     kernel = functools.partial(
         _copy_kernel, block_rows=block_rows, depth=depth, n_blocks=n_blocks,
@@ -124,7 +132,7 @@ def offload_copy_pallas(x, *, scale: float = 1.0, out_dtype=None,
             pltpu.SemaphoreType.DMA((depth,)),
             pltpu.SMEM((1, 1), jnp.float32),
         ],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x)

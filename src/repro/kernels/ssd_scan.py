@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, da_ref, dsk_ref,
                 y_ref, hout_ref, state, *, q: int, nc: int):
@@ -25,43 +23,49 @@ def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, da_ref, dsk_ref,
     def _():
         state[...] = jnp.zeros_like(state)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)       # (q, P)
-    bm = b_ref[0, :, 0, :].astype(jnp.float32)      # (q, N)
-    cm = c_ref[0, :, 0, :].astype(jnp.float32)      # (q, N)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)        # (q,)
-    da = da_ref[0, :, 0].astype(jnp.float32)        # (q,)
-    dsk = dsk_ref[0, 0]
+    x = x_ref[...].astype(jnp.float32)               # (q, P)
+    bm = b_ref[...].astype(jnp.float32)              # (q, N)
+    cm = c_ref[...].astype(jnp.float32)              # (q, N)
+    dt = dt_ref[...].astype(jnp.float32)             # (q, 1)
+    da = da_ref[...].astype(jnp.float32)             # (q, 1)
+    dsk = dsk_ref[pl.program_id(1)]
 
-    sgm = jnp.cumsum(da)                             # (q,) inclusive
-    s_last = sgm[q - 1]
-    dtx = dt[:, None] * x                            # (q, P)
+    # inclusive cumsum of da as a row and as a column, from masked
+    # reductions (no 1D vectors, no scan primitive)
+    ji = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    sgm_row = jnp.sum(jnp.where(ji <= ii, jnp.broadcast_to(da, (q, q)), 0.0),
+                      axis=0, keepdims=True)                        # (1,q)
+    sgm = jnp.sum(jnp.where(ji == ii, jnp.broadcast_to(sgm_row, (q, q)),
+                            0.0), axis=1, keepdims=True)            # (q,1)
+    s_last = jnp.sum(da, axis=0, keepdims=True)                     # (1,1)
+    dtx = dt * x                                                    # (q, P)
 
     # intra-chunk: M[j,i] = exp(s_j - s_i) (C_j . B_i), i <= j
     cb = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)   # (q,q)
-    ldiff = sgm[:, None] - sgm[None, :]
-    ji = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    m = jnp.where(ii <= ji, cb * jnp.exp(ldiff), 0.0)
+    causal = ii <= ji
+    m = jnp.where(causal, cb * jnp.exp(jnp.where(causal, sgm - sgm_row,
+                                                 0.0)), 0.0)
     y = jax.lax.dot_general(m, dtx, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)    # (q,P)
 
     # inter-chunk: y_j += exp(s_j) C_j . h_prev
     y += jax.lax.dot_general(cm, state[...], (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32) \
-        * jnp.exp(sgm)[:, None]
+        * jnp.exp(sgm)
 
     # state update: h = exp(s_last) h + B^T (decay_to_end * dtx)
-    decay = jnp.exp(s_last - sgm)[:, None]                          # (q,1)
+    decay = jnp.exp(s_last - sgm)                                   # (q,1)
     upd = jax.lax.dot_general(bm, decay * dtx, (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)   # (N,P)
     state[...] = state[...] * jnp.exp(s_last) + upd
 
-    y_ref[0, :, 0, :] = (y + dsk * x).astype(y_ref.dtype)
+    y_ref[...] = (y + dsk * x).astype(y_ref.dtype)
 
     @pl.when(ci == nc - 1)
     def _():
-        hout_ref[0, 0, :, :] = state[...].astype(hout_ref.dtype)
+        hout_ref[...] = state[...].astype(hout_ref.dtype)
 
 
 def ssd_scan_pallas(xh, bm, cm, dt, da, d_skip, *, chunk: int = 256,
@@ -69,6 +73,11 @@ def ssd_scan_pallas(xh, bm, cm, dt, da, d_skip, *, chunk: int = 256,
     """xh (B,S,H,P); bm/cm (B,S,G,N); dt/da (B,S,H); d_skip (H,).
 
     Returns (y (B,S,H,P) fp32, h_final (B,H,N,P) fp32).
+
+    The kernel runs head-major: each block is a (chunk, width) tile with
+    batch and head squeezed out, dt/da are (chunk, 1) columns and the skip
+    weights sit whole in SMEM, so every block meets the TPU's (8,128)
+    tiling rule for any head count.
     """
     b, s, nh, p = xh.shape
     g, n = bm.shape[2], bm.shape[3]
@@ -78,30 +87,31 @@ def ssd_scan_pallas(xh, bm, cm, dt, da, d_skip, *, chunk: int = 256,
     nc = s // q
 
     kernel = functools.partial(_ssd_kernel, q=q, nc=nc)
-    dsk = d_skip.reshape(nh, 1).astype(jnp.float32)
+    heads = lambda a: a.transpose(0, 2, 1, 3)        # (B,S,H,·) <-> (B,H,S,·)
+    col = lambda a: heads(a[..., None])              # (B,S,H) -> (B,H,S,1)
+    sq = pl.squeezed
+
+    def tile(width, group=1):
+        return pl.BlockSpec((sq, sq, q, width),
+                            lambda bi, hi, ci: (bi, hi // group, ci, 0))
 
     y, hf = pl.pallas_call(
         kernel,
         grid=(b, nh, nc),
-        in_specs=[
-            pl.BlockSpec((1, q, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, q, 1, n), lambda bi, hi, ci: (bi, ci, hi // hg, 0)),
-            pl.BlockSpec((1, q, 1, n), lambda bi, hi, ci: (bi, ci, hi // hg, 0)),
-            pl.BlockSpec((1, q, 1), lambda bi, hi, ci: (bi, ci, hi)),
-            pl.BlockSpec((1, q, 1), lambda bi, hi, ci: (bi, ci, hi)),
-            pl.BlockSpec((1, 1), lambda bi, hi, ci: (hi, 0)),
-        ],
+        in_specs=[tile(p), tile(n, hg), tile(n, hg), tile(1), tile(1),
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[
-            pl.BlockSpec((1, q, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, 1, n, p), lambda bi, hi, ci: (bi, hi, 0, 0)),
+            tile(p),
+            pl.BlockSpec((sq, sq, n, p), lambda bi, hi, ci: (bi, hi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, s, nh, p), jnp.float32),
+            jax.ShapeDtypeStruct((b, nh, s, p), jnp.float32),
             jax.ShapeDtypeStruct((b, nh, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(xh, bm, cm, dt, da, dsk)
-    return y, hf
+    )(heads(xh), heads(bm), heads(cm), col(dt), col(da),
+      d_skip.astype(jnp.float32))
+    return heads(y), hf

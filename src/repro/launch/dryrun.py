@@ -28,7 +28,7 @@ from repro.configs.base import Cell
 from repro.launch import hlo as hlo_mod
 from repro.launch import jcost
 from repro.launch import specs as specs_mod
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models.registry import build_model
 from repro.optim import adamw
 from repro.sharding import api as shard_api
@@ -250,7 +250,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         if movement.startswith("tp8"):
             # same 256 chips, lower TP degree: activation psums shrink with
             # the per-device activation slice (§Perf prefill exploration)
-            mesh = jax.make_mesh((32, 8), ("data", "model"))
+            mesh = make_mesh((32, 8), ("data", "model"))
         else:
             mesh = make_production_mesh(multi_pod=multi_pod)
         with shard_api.use_mesh(mesh):

@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.core import ExecutionMode, OffloadPolicy
+from repro.launch.cache import use_compile_cache
 from repro.models import build_model
 from repro.serve import BatchedServer, ServeConfig
 
@@ -29,6 +30,7 @@ def main() -> None:
     ap.add_argument("--mode", default="pipelined",
                     choices=["sync", "async", "pipelined"])
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)

@@ -25,6 +25,8 @@ from repro.core import ExecutionMode, LatencyModel, OffloadPolicy
 from repro.core.latency import calibrate
 from repro.data import InputPipeline, SyntheticLMSource
 from repro.ft import Heartbeat, RestartManager, StragglerMonitor
+from repro.launch.cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import adamw
 from repro.sharding import api as shard_api
@@ -54,6 +56,7 @@ def main() -> None:
     ap.add_argument("--calibrate", action="store_true",
                     help="recalibrate the latency model on this node")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
@@ -62,7 +65,7 @@ def main() -> None:
     manual_axes = ()
     if args.movement.startswith("manual_dp"):
         # manual-DP over however many devices this host has
-        mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+        mesh = make_mesh((len(jax.devices()),), ("data",))
         shard_api.set_mesh(mesh)
         manual_axes = ("data",)
     tcfg = TrainConfig(

@@ -18,8 +18,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 from repro.configs.base import ModelConfig
 from repro.models.layers import (
     apply_rope,
@@ -364,7 +362,7 @@ def sp_decode_attention(q, layer_k, layer_v, k_new, v_new, cfg: ModelConfig,
         return o_all, l_all, m_all
 
     with shard_api.manual_mode():
-        o, l, m = compat.shard_map(
+        o, l, m = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(bx), P(bx, axis, None, None),
                       P(bx, axis, None, None), P(bx)),

@@ -4,7 +4,9 @@ GShard-style grouped one-hot dispatch (einsum form) so GSPMD can shard the
 expert dimension over the ``model`` axis (expert parallelism) and insert the
 dispatch collectives.  Tokens are processed in groups of ``GROUP_SIZE`` to
 bound the dispatch-tensor working set (the same size-threshold discipline
-the paper applies to offloaded transfers).
+the paper applies to offloaded transfers).  A ragged tail is padded into
+a last group whose pad slots are masked out of routing: they take no expert
+capacity and get no output.
 """
 from __future__ import annotations
 
@@ -47,10 +49,11 @@ def moe_apply(params, x, cfg: ModelConfig):
     """x: (B, S, D) -> (y, aux_loss). Top-k routing with capacity dropping."""
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_token
-    tokens = x.reshape(b * s, d)
-    tg = min(GROUP_SIZE, b * s)
-    ng = (b * s) // tg
-    xt = tokens[: ng * tg].reshape(ng, tg, d)
+    n = b * s
+    tg = min(GROUP_SIZE, n)
+    ng = -(-n // tg)
+    xt = jnp.pad(x.reshape(n, d), ((0, ng * tg - n), (0, 0))).reshape(ng, tg, d)
+    valid = (jnp.arange(ng * tg) < n).reshape(ng, tg)
     cap = expert_capacity(tg, cfg)
 
     # --- routing (fp32) -----------------------------------------------------
@@ -66,7 +69,8 @@ def moe_apply(params, x, cfg: ModelConfig):
     combine = jnp.zeros((ng, tg, e, cap), jnp.float32)
     counts = jnp.zeros((ng, e), jnp.int32)
     for kk in range(k):
-        m = jax.nn.one_hot(idx[..., kk], e, dtype=jnp.int32)          # (g,t,e)
+        m = jax.nn.one_hot(idx[..., kk], e, dtype=jnp.int32) \
+            * valid[..., None]                                         # (g,t,e)
         pos = jnp.cumsum(m, axis=1) - m + counts[:, None, :]          # (g,t,e)
         keep = (pos < cap) & (m > 0)
         poh = jax.nn.one_hot(pos, cap, dtype=x.dtype) * keep[..., None].astype(x.dtype)
@@ -88,13 +92,10 @@ def moe_apply(params, x, cfg: ModelConfig):
 
     # --- load-balancing aux loss (switch-style) -------------------------------
     # fraction of tokens whose top-1 choice is expert e  ×  mean router prob
+    w = valid[..., None].astype(jnp.float32) / n
     top1 = jax.nn.one_hot(idx[..., 0], e, dtype=jnp.float32)
-    frac = jnp.mean(top1, axis=(0, 1))
-    mean_p = jnp.mean(probs, axis=(0, 1))
+    frac = jnp.sum(top1 * w, axis=(0, 1))
+    mean_p = jnp.sum(probs * w, axis=(0, 1))
     aux = e * jnp.sum(frac * mean_p)
 
-    y = y.reshape(ng * tg, d)
-    if ng * tg < b * s:                                      # ragged tail
-        tail = tokens[ng * tg:]
-        y = jnp.concatenate([y, jnp.zeros_like(tail)], axis=0)
-    return y.reshape(b, s, d), aux
+    return y.reshape(ng * tg, d)[:n].reshape(b, s, d), aux

@@ -10,7 +10,6 @@ ROCKET integration:
 """
 from __future__ import annotations
 
-import functools
 import time
 from dataclasses import dataclass
 from typing import Optional
@@ -46,10 +45,17 @@ class BatchedServer:
         self.scfg = scfg
         self.policy = policy
         self.engine = AsyncTransferEngine(policy)
-        self._prefill = jax.jit(
-            functools.partial(model.prefill, max_len=scfg.max_len))
+        # named steps: compile events and profiler traces report them as
+        # jit(prefill) / jit(decode)
+        def prefill(params, batch):
+            return model.prefill(params, batch, max_len=scfg.max_len)
+
+        def decode(params, cache, tok):
+            return model.decode_step(params, cache, tok)
+
+        self._prefill = jax.jit(prefill)
         # cache donated: the persistent decode buffer is reused in place
-        self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
+        self._decode = jax.jit(decode, donate_argnums=(1,))
         self.stats = {"requests": 0, "batches": 0, "tokens_out": 0,
                       "prefill_s": 0.0, "decode_s": 0.0}
 

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from repro.sharding import api as shard_api
 
 
@@ -80,7 +78,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *, axis: str,
 
     param_specs = jax.tree.map(lambda _: P(axis), stage_params)
     with shard_api.manual_mode():
-        out = compat.shard_map(
+        out = jax.shard_map(
             per_stage, mesh=mesh,
             in_specs=(param_specs, P()),
             out_specs=P(), check_vma=False,

@@ -14,8 +14,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 from repro.models.registry import ModelAPI
 from repro.optim import adamw
 from repro.sharding import api as shard_api
@@ -114,7 +112,7 @@ def _manual_dp_grads(model, tcfg, grads_of, params, batch):
                                                    "tokens": 0}),
                  jax.tree.map(lambda _: P(), params))
     with shard_api.manual_mode():
-        return compat.shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(param_specs, batch_specs),
             out_specs=out_specs, check_vma=False)(params, batch)

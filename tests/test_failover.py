@@ -337,3 +337,56 @@ def test_stalled_promotion_falls_back_to_cold_restart():
     finally:
         sup.close()
     assert [f for f in os.listdir(SHM_DIR) if f.startswith(name)] == []
+
+
+SUPERVISOR_PARENT = """
+import os, signal, sys
+import numpy as np
+from jax._src import xla_bridge
+from repro.core.policy import OffloadPolicy, RetryPolicy
+from repro.ft.supervisor import FabricSupervisor
+from repro.ipc.worker import RemoteDispatcherClient
+
+name, factory = sys.argv[1], sys.argv[2]
+pol = OffloadPolicy(mode="pipelined", retry=RetryPolicy(
+    heartbeat_interval_s=0.05, heartbeat_stale_s=0.3,
+    connect_timeout_s=5.0, max_reconnects=6))
+sup = FabricSupervisor(name, factory, policy=pol, max_restarts=2,
+                       standby_factory=factory, standby_interval_s=0.05,
+                       promote_timeout_s=20.0).start()
+try:
+    assert sup.wait_alive(30.0)
+    cli = RemoteDispatcherClient.connect(name, policy=pol, timeout_s=30.0)
+    try:
+        vec = np.arange(8, dtype=np.float64)
+        for i in range(6):
+            if i == 3:
+                os.kill(sup._proc.pid, signal.SIGKILL)
+            assert np.array_equal(cli.request("double", vec + i, mode="sync"),
+                                  (vec + i) * 2)
+    finally:
+        cli.close()
+    s = sup.stats()
+    assert s["crashes"] == 1 and s["promotions"] + s["restarts"] == 1, s
+finally:
+    sup.close()
+print("BACKENDS_INITIALIZED", xla_bridge.backends_are_initialized())
+"""
+
+
+@pytest.mark.slow
+def test_supervisor_parent_never_initializes_a_jax_backend():
+    """The supervisor's children take the chip, so the supervising parent
+    (spawn, watch, promote or restart, close) must never initialize a JAX
+    backend itself: a fresh interpreter drives a crash + recovery and
+    reports its own backend state."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(root, "src"))
+    out = subprocess.run(
+        [sys.executable, "-c", SUPERVISOR_PARENT, _name("fo-nojax"), FACTORY],
+        capture_output=True, text=True, timeout=240, env=env, cwd=root)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    assert "BACKENDS_INITIALIZED False" in out.stdout, out.stdout[-2000:]

@@ -130,7 +130,8 @@ def test_ops_threshold_dispatch(rng_key):
     from repro.kernels import ops
     x = jax.random.normal(rng_key, (256, 128))
     small_policy = OffloadPolicy(offload_threshold_bytes=1 << 30)  # never
-    y1, _ = ops.offload_copy(x, policy=small_policy)
+    y1, _ = ops.offload_copy(x, policy=small_policy, interpret=True)
     y2, _ = ops.offload_copy(
-        x, policy=OffloadPolicy(offload_threshold_bytes=1))       # always
+        x, policy=OffloadPolicy(offload_threshold_bytes=1),        # always
+        interpret=True)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-6)

@@ -87,3 +87,40 @@ def test_group_tail_handling(rng_key):
         assert y.shape == x.shape
     finally:
         moe.GROUP_SIZE = old
+
+
+def _per_token_topk(params, x, cfg):
+    """Plain reference: each token alone through its top-k experts."""
+    tokens = np.asarray(x, np.float64).reshape(-1, cfg.d_model)
+    p = {k: np.asarray(v, np.float64) for k, v in params.items()}
+    logits = tokens @ p["router"]
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    out = np.zeros_like(tokens)
+    for i, tok in enumerate(tokens):
+        top = np.argsort(-probs[i])[: cfg.num_experts_per_token]
+        gates = probs[i, top] / probs[i, top].sum()
+        for g, ex in zip(gates, top):
+            hg = tok @ p["we_g"][ex]
+            h = hg / (1.0 + np.exp(-hg)) * (tok @ p["we_u"][ex])
+            out[i] += g * (h @ p["we_d"][ex])
+    return out.reshape(x.shape)
+
+
+@pytest.mark.parametrize("group,seq", [(8, 13), (16, 20), (512, 300)])
+def test_dropless_matches_per_token_reference_with_ragged_tail(
+        rng_key, group, seq):
+    """At a capacity that drops nothing, every token -- the ragged tail's
+    included -- equals the plain per-token top-k reference."""
+    cfg = mk_cfg(e=4, k=2, cf=2.0)         # capacity == group: dropless
+    params = moe.moe_init(rng_key, cfg)
+    x = jax.random.normal(jax.random.key(6), (2, seq, cfg.d_model))
+    assert (2 * seq) % group and moe.expert_capacity(group, cfg) == group
+    old = moe.GROUP_SIZE
+    try:
+        moe.GROUP_SIZE = group
+        y, _ = moe.moe_apply(params, x, cfg)
+    finally:
+        moe.GROUP_SIZE = old
+    np.testing.assert_allclose(np.asarray(y), _per_token_topk(params, x, cfg),
+                               rtol=1e-4, atol=1e-5)

@@ -9,6 +9,7 @@ import pytest
 from repro.configs import get_smoke_config
 from repro.configs.base import ShapeConfig
 from repro.data import SyntheticLMSource
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.models import attention as attn
 from repro.models.transformer import lm_decode_step_inplace
@@ -19,7 +20,7 @@ from repro.train import TrainConfig, init_train_state, make_train_step
 
 @pytest.fixture()
 def unit_mesh():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with shard_api.use_mesh(mesh):
         yield mesh
 

@@ -13,10 +13,11 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.sharding import api as shard_api
     from repro.sharding.pipeline import pipeline_apply, sequential_apply
 
-    mesh = jax.make_mesh((4, 2), ("stage", "data"))
+    mesh = make_mesh((4, 2), ("stage", "data"))
     n_stages, b, d = 4, 8, 16
     key = jax.random.key(0)
     ws = 0.3 * jax.random.normal(key, (n_stages, d, d))

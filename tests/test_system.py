@@ -1,5 +1,6 @@
 """End-to-end behaviour: training learns, checkpoint-resume is exact,
 serving round-trips through the dispatcher, dry-run machinery works."""
+import json
 import os
 import subprocess
 import sys
@@ -117,7 +118,8 @@ def test_dryrun_cell_subprocess():
     """The real dry-run machinery on the production mesh (512 host devices),
     via subprocess so the main test process keeps 1 device."""
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    # the 512 devices are host-platform devices: never probe for a TPU
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = "src"
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun",
@@ -126,3 +128,30 @@ def test_dryrun_cell_subprocess():
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
     assert "[ok" in out.stdout
+
+
+@pytest.mark.parametrize("argv,ok", [
+    (["--platform", "cpu", "--smoke"], True),   # the CPU rehearsal
+    ([], False),                                # no TPU here: must fail
+])
+def test_chip_smoke_script(tmp_path, argv, ok):
+    """``chip_smoke.py`` drives the served path end to end (3 client
+    processes over the fabric, sync replies byte-identical to direct
+    generation, cross-client batching); without a chip it fails and prints
+    no result line."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"))
+    out = subprocess.run([sys.executable, "chip_smoke.py", *argv],
+                         capture_output=True, text=True, timeout=300,
+                         env=env, cwd=root)
+    lines = out.stdout.strip().splitlines()
+    if not ok:
+        assert out.returncode != 0
+        assert not any(l.startswith('{"ok"') for l in lines)
+        return
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    assert json.loads(lines[-1]) == {
+        "ok": True, "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
+    assert any(l.startswith("phase A: 6 sync replies byte-identical")
+               for l in lines)
