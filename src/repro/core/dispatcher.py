@@ -84,7 +84,9 @@ class Request:
     op: str
     data: Any
     mode: ExecutionMode
-    submit_t: float = field(default_factory=time.perf_counter)
+    # arrival, perf_counter_ns (the tracer's clock): starts the request's
+    # dispatcher.queue span, and the query deferral counts from it
+    submit_ns: int = field(default_factory=time.perf_counter_ns)
     nbytes: int = 0
     # completion callback (multi-client serving): when set, the worker thread
     # calls ``callback(job_id, result_or_exception)`` instead of parking the
@@ -383,6 +385,9 @@ class QueryHandler:
         self._results: dict[int, Any] = {}
         self._events: dict[int, threading.Event] = {}
         self._meta: dict[int, Request] = {}
+        # traced waits: job id -> (start ns, thread CPU ns) of the first
+        # query() call, so a wait made in slices is one span and one record
+        self._waits: dict[int, tuple[int, int]] = {}
         self._lock = threading.Lock()
         self.latency = latency
         self.policy = policy
@@ -404,12 +409,15 @@ class QueryHandler:
         with self._lock:
             ev = self._events.get(job_id)
             req = self._meta.get(job_id)
+            if (_trace.TRACE.enabled and ev is not None
+                    and job_id not in self._waits):
+                self._waits[job_id] = (_trace.now(), time.thread_time_ns())
         if ev is None:
             raise KeyError(f"unknown job {job_id}")
         if not ev.is_set() and req is not None:
             # size-aware deferral before polling (remaining predicted latency)
             pred = self.latency.defer_seconds(req.nbytes, self.policy.defer_fraction)
-            remain = pred - (time.perf_counter() - req.submit_t)
+            remain = pred - (time.perf_counter_ns() - req.submit_ns) / 1e9
             if remain > 0:
                 time.sleep(min(remain, timeout))
         deadline = time.perf_counter() + timeout
@@ -423,6 +431,13 @@ class QueryHandler:
             out = self._results.pop(job_id)
             self._events.pop(job_id, None)
             self._meta.pop(job_id, None)
+            wait = self._waits.pop(job_id, None)
+        if wait is not None:
+            # one span and one CPU record per completed wait: the polling
+            # above is where a waiting client's CPU goes
+            rid = req.rid if req is not None else 0
+            _trace.emit(_trace.QUERY_WAIT, wait[0], rid=rid)
+            _hw.emit_thread_cpu(_trace.QUERY_WAIT, wait[0], wait[1], rid=rid)
         return out
 
 
@@ -458,6 +473,7 @@ class RequestDispatcher:
         self._pool = BufferPool(max_per_key=4)   # pooled batch buffers
         self._q = _LaneQueue()
         self._ids = itertools.count()
+        self._batch_seq = itertools.count(1)     # traced batches' numbers
         self._max_wait = max_batch_wait_s
         self._slock = threading.Lock()           # stats (workers > 1 race)
         self._running = True
@@ -752,15 +768,29 @@ class RequestDispatcher:
 
     # -- server loop -----------------------------------------------------------
     def _serve_loop(self) -> None:
+        # traced, the worker's time is covered end to end: idle (blocked
+        # on an empty queue), batch_wait, handler, complete
+        meter = _hw.LoopMeter(_trace.DISPATCH_LOOP)
+        idle0 = 0
         while self._running:
+            if _trace.TRACE.enabled:
+                meter.tick()
+                idle0 = idle0 or _trace.now()
             try:
                 req = self._q.get(timeout=0.1)
             except queue.Empty:
-                continue
+                continue            # one idle span per empty stretch
+            if idle0:
+                _trace.emit(_trace.DISPATCH_IDLE, idle0)
+                idle0 = 0
             if req is None:
                 break
             if self._maybe_shed(req):
                 continue
+            seq = next(self._batch_seq) if _trace.TRACE.enabled else 0
+            if seq:
+                _trace.emit(_trace.DISPATCH_QUEUE, req.submit_ns,
+                            rid=req.rid, arg=seq)
             if req.mode == ExecutionMode.PIPELINED:
                 t0 = _trace.now() if _trace.TRACE.enabled else 0
                 c0 = _hw.begin() if _hw.PROF.enabled else None
@@ -789,6 +819,9 @@ class RequestDispatcher:
                         break
                     if self._maybe_shed(nxt):
                         continue
+                    if seq:
+                        _trace.emit(_trace.DISPATCH_QUEUE, nxt.submit_ns,
+                                    rid=nxt.rid, arg=seq)
                     batch.append(nxt)
                 if t0:      # the batch-formation window wait, per batch
                     _trace.emit(_trace.DISPATCH_WAIT, t0, rid=batch[0].rid,
@@ -798,6 +831,9 @@ class RequestDispatcher:
                 self._execute(batch)
             else:
                 self._execute([req])
+        if idle0:
+            _trace.emit(_trace.DISPATCH_IDLE, idle0)
+        meter.flush()
 
     # -- batch formation: slot views → pooled batch buffer ---------------------
     #: ceiling on one pooled gather slab: with the bulk-heap datapath a
@@ -939,8 +975,9 @@ class RequestDispatcher:
                         results.append(e)
                         self._breaker_note(br, False)
             if t0:      # batch compute: gather (nested sub-span) + handler
+                t_done = _trace.now()
                 _trace.emit(_trace.HANDLER, t0, rid=batch[0].rid,
-                            arg=len(batch))
+                            arg=len(batch), t1=t_done)
             if c0 is not None:
                 # like the HANDLER span, this contains sg_gather as a
                 # nested sub-scope; handler-only = handler − sg_gather
@@ -967,6 +1004,9 @@ class RequestDispatcher:
                         and np.may_share_memory(out, r.data)):
                     out = np.array(out)
                 self._complete(r, out)
+            if t0:      # completion callbacks: the replies to the clients
+                _trace.emit(_trace.DISPATCH_COMPLETE, t_done,
+                            rid=batch[0].rid, arg=len(batch))
         finally:
             # solo/fallback paths executed on the leased views directly:
             # release only now, after replies/results are materialized

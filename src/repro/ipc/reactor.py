@@ -376,7 +376,12 @@ class Reactor:
         quantum = self.policy.poll_interval_us * 1e-6
         spin_s = self.policy.spin_us * 1e-6
         spin_deadline = time.perf_counter() + spin_s
+        # traced, the thread's CPU (spins and quantum sleeps included) is
+        # emitted per stretch of the loop, not per sweep
+        meter = _hw.LoopMeter(_trace.REACTOR_LOOP)
         while not self._stop.is_set():
+            if _trace.TRACE.enabled:
+                meter.tick()
             if self.poll_once() > 0:
                 spin_deadline = time.perf_counter() + spin_s
                 continue
@@ -385,6 +390,7 @@ class Reactor:
             else:
                 self.stats.idle_sleeps += 1
                 time.sleep(quantum)     # quantum phase: stay CPU-polite
+        meter.flush()
 
     # -- lifecycle ------------------------------------------------------------
     def start(self) -> "Reactor":

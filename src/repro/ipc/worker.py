@@ -613,7 +613,6 @@ class RemoteDispatcherClient:
         # 32-bit session nonce: scopes idempotent ids to this client life
         self.session_id = int.from_bytes(os.urandom(4), "little") or 1
         self._ids = iter(range(1, 1 << 62))
-        self._rids: dict[int, int] = {}    # job_id -> trace request id
         self._lock = threading.Lock()
         self._recv_thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
@@ -669,7 +668,10 @@ class RemoteDispatcherClient:
 
     def _recv_loop(self) -> None:
         poll_s = self.policy.retry.recv_poll_s
+        meter = _hw.LoopMeter(_trace.RECV_LOOP)   # thread CPU, when traced
         while not self._stop.is_set():
+            if _trace.TRACE.enabled:
+                meter.tick()
             failed = False
             with self._transport_lock:
                 transport = self.transport
@@ -715,6 +717,7 @@ class RemoteDispatcherClient:
                     self._completed.discard(self._completed_q.popleft())
                 self._unacked.pop(job_id, None)
             self.queries.complete(job_id, result)
+        meter.flush()
 
     # -- crash recovery -------------------------------------------------------
     def reconnect(self, deadline: Optional[float] = None) -> None:
@@ -829,13 +832,12 @@ class RemoteDispatcherClient:
             # dispatcher, handler, reply) joins on it across processes
             rid = _trace.mint_rid()
             header[_trace.RID_KEY] = rid
-            self._rids[job_id] = rid
         # all modes go through the receiver thread + QueryHandler: replies
         # are matched by job_id, so concurrent client threads can't steal
         # each other's results off the SPSC rx ring
         self._ensure_receiver()
         self.queries.register(Request(job_id, op, None, mode,
-                                      nbytes=int(data.nbytes)))
+                                      nbytes=int(data.nbytes), rid=rid))
         # track as unacked BEFORE the send: if the transport dies inside
         # send(), the reconnect replay below already covers this request
         with self._lock:
@@ -878,89 +880,81 @@ class RemoteDispatcherClient:
             if self._listener_name is None:
                 raise
             self.reconnect()
-        rid = self._rids.pop(job_id, 0) if _trace.TRACE.enabled else 0
-        span = _trace.span(_trace.QUERY_WAIT, rid=rid) if rid else None
-        if span is not None:
-            span.__enter__()
-        try:
-            deadline = time.perf_counter() + timeout
-            retry = self.policy.retry
-            # wait in heartbeat-interval slices (not stale_s slices): the
-            # staleness check below only runs at slice boundaries, so a
-            # coarser slice would quantize failure detection to up to
-            # 2x stale_s depending on heartbeat phase at the crash
-            slice_s = max(retry.heartbeat_interval_s, 0.05)
-            resubmits = 0
-            # single-request resubmit patience: a slice is too short to
-            # conclude a reply was dropped (it may simply be in flight),
-            # so re-send only after a full stale window of silence
-            last_send = time.perf_counter()
-            while True:
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    with self._lock:
-                        lost = self._unacked.pop(job_id, None) is not None
-                    if lost:
-                        self.lost_replies += 1
-                    raise TimeoutError(f"job {job_id} timed out")
+        # the wait emits its client.query_wait span in QueryHandler.query
+        deadline = time.perf_counter() + timeout
+        retry = self.policy.retry
+        # wait in heartbeat-interval slices (not stale_s slices): the
+        # staleness check below only runs at slice boundaries, so a
+        # coarser slice would quantize failure detection to up to
+        # 2x stale_s depending on heartbeat phase at the crash
+        slice_s = max(retry.heartbeat_interval_s, 0.05)
+        resubmits = 0
+        # single-request resubmit patience: a slice is too short to
+        # conclude a reply was dropped (it may simply be in flight),
+        # so re-send only after a full stale window of silence
+        last_send = time.perf_counter()
+        while True:
+            remaining = deadline - time.perf_counter()
+            if remaining <= 0:
+                with self._lock:
+                    lost = self._unacked.pop(job_id, None) is not None
+                if lost:
+                    self.lost_replies += 1
+                raise TimeoutError(f"job {job_id} timed out")
+            try:
+                out = self.queries.query(job_id, min(remaining, slice_s))
+                break
+            except TimeoutError:
+                # mid-wait failure detection: a stale server heartbeat
+                # (or dead transport) triggers reconnect + replay here
+                # rather than burning the rest of the timeout
+                if self._listener_name is None:
+                    continue
                 try:
-                    out = self.queries.query(job_id,
-                                             min(remaining, slice_s))
-                    break
-                except TimeoutError:
-                    # mid-wait failure detection: a stale server heartbeat
-                    # (or dead transport) triggers reconnect + replay here
-                    # rather than burning the rest of the timeout
-                    if self._listener_name is None:
-                        continue
+                    stale = self.transport.peer_stale()
+                except Exception:
+                    stale = True       # transport already torn down
+                if stale:
                     try:
-                        stale = self.transport.peer_stale()
+                        # bound the cumulative reconnect wait by this
+                        # query's own deadline: a promotion/restart
+                        # that overruns it becomes a typed error now,
+                        # not a silent over-wait
+                        self.reconnect(deadline=deadline)
+                    except ReconnectTimeout:
+                        with self._lock:
+                            lost = (self._unacked.pop(job_id, None)
+                                    is not None)
+                        if lost:
+                            self.lost_replies += 1
+                        raise
+                    except ConnectionError:
+                        pass
+                    last_send = time.perf_counter()  # replay counts
+                    continue
+                # server alive but this request never answered — the
+                # request (or its reply) was dropped in transit (e.g.
+                # quarantined as corrupt).  Bounded single-request
+                # resubmit, idempotent by dedup id, and only after a
+                # full stale window of silence since the last send —
+                # one elapsed slice just means the reply is in flight.
+                if (time.perf_counter() - last_send
+                        < retry.heartbeat_stale_s):
+                    continue
+                with self._lock:
+                    entry = self._unacked.get(job_id)
+                if entry is not None \
+                        and resubmits < retry.max_reconnects:
+                    hdr, payload = entry
+                    try:
+                        self.transport.send({"data": payload},
+                                            header=dict(hdr),
+                                            mode="sync")
                     except Exception:
-                        stale = True       # transport already torn down
-                    if stale:
-                        try:
-                            # bound the cumulative reconnect wait by this
-                            # query's own deadline: a promotion/restart
-                            # that overruns it becomes a typed error now,
-                            # not a silent over-wait
-                            self.reconnect(deadline=deadline)
-                        except ReconnectTimeout:
-                            with self._lock:
-                                lost = (self._unacked.pop(job_id, None)
-                                        is not None)
-                            if lost:
-                                self.lost_replies += 1
-                            raise
-                        except ConnectionError:
-                            pass
-                        last_send = time.perf_counter()  # replay counts
                         continue
-                    # server alive but this request never answered — the
-                    # request (or its reply) was dropped in transit (e.g.
-                    # quarantined as corrupt).  Bounded single-request
-                    # resubmit, idempotent by dedup id, and only after a
-                    # full stale window of silence since the last send —
-                    # one elapsed slice just means the reply is in flight.
-                    if (time.perf_counter() - last_send
-                            < retry.heartbeat_stale_s):
-                        continue
-                    with self._lock:
-                        entry = self._unacked.get(job_id)
-                    if entry is not None \
-                            and resubmits < retry.max_reconnects:
-                        hdr, payload = entry
-                        try:
-                            self.transport.send({"data": payload},
-                                                header=dict(hdr),
-                                                mode="sync")
-                        except Exception:
-                            continue
-                        resubmits += 1
-                        self.retries += 1
-                        last_send = time.perf_counter()
-        finally:
-            if span is not None:
-                span.__exit__(None, None, None)
+                    resubmits += 1
+                    self.retries += 1
+                    last_send = time.perf_counter()
         if isinstance(out, Exception):
             raise out
         return out

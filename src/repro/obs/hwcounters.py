@@ -39,6 +39,11 @@ trace rings (kinds ≥ `trace.CTR_FIRST`, delta stored as `t1 - t0`, the
 phase kind in `arg`, the request id in `rid`) — so counters join the
 cross-process trace export with no new machinery.
 
+Polling loops and completion waits are metered on span tracing alone:
+:class:`LoopMeter` and :func:`emit_thread_cpu` emit the thread's CPU
+(`time.thread_time_ns`) as `task_clock_ns` records of the same form,
+profiling on or off.
+
 Usage::
 
     from repro.obs import hwcounters as hw
@@ -151,8 +156,18 @@ PHASES = {
     "publish": _trace.CH_PUBLISH,
     "governor": _trace.GOV_DECIDE,
     "reply_drain": _trace.CLIENT_RECV,
+    # whole polling loops and completion waits (see LoopMeter): metered
+    # from thread CPU whenever span tracing is on, profiling or not
+    "query_wait": _trace.QUERY_WAIT,
+    "reactor_loop": _trace.REACTOR_LOOP,
+    "recv_loop": _trace.RECV_LOOP,
+    "dispatcher_loop": _trace.DISPATCH_LOOP,
 }
 _PHASE_BY_KIND = {v: k for k, v in PHASES.items()}
+
+#: a metered loop emits one CPU record per at least this much wall time
+LOOP_PERIOD_NS = 50_000_000
+_TASK_CLOCK = _trace.CTR_KINDS["task_clock_ns"]
 
 _libc = None
 
@@ -579,6 +594,57 @@ class CounterScope:
         token, self._token = self._token, None
         if token is not None:
             end(token, self.phase, nbytes=self.nbytes, rid=self.rid)
+
+
+def emit_thread_cpu(kind: int, t0: int, cpu0: int, rid: int = 0) -> int:
+    """Emit the calling thread's CPU since ``cpu0`` (a
+    ``time.thread_time_ns()`` reading) as one ``task_clock_ns`` counter
+    record starting at ``t0``, ``arg`` = the phase kind; returns the
+    reading it ended at.  Span tracing alone carries it (no
+    ``PROF.enabled``): callers guard with ``TRACE.enabled`` as for any
+    span."""
+    cpu = time.thread_time_ns()
+    _trace.emit(_TASK_CLOCK, t0, rid=rid, arg=kind,
+                t1=t0 + max(cpu - cpu0, 0))
+    return cpu
+
+
+class LoopMeter:
+    """Thread CPU of one polling loop, as ``task_clock_ns`` counter records.
+
+    The phase scopes above meter non-empty drains only; the spinning and
+    quantum sleeps between them are where a polling loop's CPU goes.  The
+    loop calls :meth:`tick` once per iteration behind the
+    ``TRACE.enabled`` guard (one ``perf_counter_ns`` read); every
+    ``LOOP_PERIOD_NS`` of wall time one record carries the thread's CPU
+    over that stretch, so the cost is one ``thread_time_ns`` read per
+    record, not per iteration.  :meth:`flush` emits the rest when the loop
+    ends.  A loop record contains any phase scope of the same thread."""
+
+    __slots__ = ("kind", "_t0", "_cpu0")
+
+    def __init__(self, kind: int):
+        self.kind = kind
+        self._t0 = 0
+        self._cpu0 = 0
+
+    def tick(self) -> None:
+        """One loop iteration (call only while tracing is on)."""
+        t = _trace.now()
+        if not self._t0:
+            self._t0, self._cpu0 = t, time.thread_time_ns()
+        elif t - self._t0 >= LOOP_PERIOD_NS:
+            self._emit(t)
+
+    def flush(self) -> None:
+        """Emit the open stretch (the loop is ending)."""
+        if self._t0:
+            self._emit(_trace.now())
+            self._t0 = 0
+
+    def _emit(self, t: int) -> None:
+        self._cpu0 = emit_thread_cpu(self.kind, self._t0, self._cpu0)
+        self._t0 = t
 
 
 class Meter:

@@ -69,7 +69,7 @@ RID_KEY = "__rocket_rid__"
 # -- span kinds -------------------------------------------------------------
 CLIENT_SEND = 1        # RemoteDispatcherClient.request: send on the wire
 CLIENT_RECV = 2        # reply decoded client-side (instant)
-QUERY_WAIT = 3         # RemoteDispatcherClient.query: wait for completion
+QUERY_WAIT = 3         # QueryHandler.query: one wait for completion
 CH_SEND = 4            # DataChannel.send wall time (any route)
 CH_PUBLISH = 5         # slot claim→publish→doorbell inside _publish
 RING_WAIT = 6          # ring slow path: blocked on a slot state flip
@@ -83,6 +83,19 @@ GOV_DECIDE = 13        # governor route decision
 GOV_OBSERVE = 14       # governor cost observation (instant)
 COPY_JOB = 15          # one CopyEngine SG descriptor's memcpy loop
 SERVE_BATCH = 16       # BatchedServer.generate_batch (prefill+decode)
+DISPATCH_QUEUE = 17    # one request: arrival in submit → popped into a
+                       # batch (arg = the dispatcher's batch sequence no.)
+DISPATCH_IDLE = 18     # dispatcher worker blocked on an empty queue
+DISPATCH_COMPLETE = 19 # completion callbacks and replies after the handler
+SERVE_H2D = 20         # generate_batch: host→device copy of the batch
+SERVE_PREFILL = 21     # generate_batch: dispatch of jit(prefill)
+SERVE_DECODE = 22      # generate_batch: dispatch of the decode loop
+SERVE_SYNC = 23        # generate_batch: wait for the result on the host
+# polling loops: phase kinds of their CPU counter records only (a loop's
+# wall time is the whole life of its thread, so it gets no span)
+REACTOR_LOOP = 24      # Reactor._loop
+RECV_LOOP = 25         # RemoteDispatcherClient._recv_loop
+DISPATCH_LOOP = 26     # RequestDispatcher._serve_loop
 
 KIND_NAMES = {
     CLIENT_SEND: "client.send",
@@ -101,6 +114,16 @@ KIND_NAMES = {
     GOV_OBSERVE: "governor.observe",
     COPY_JOB: "copyengine.copy",
     SERVE_BATCH: "serve.generate_batch",
+    DISPATCH_QUEUE: "dispatcher.queue",
+    DISPATCH_IDLE: "dispatcher.idle",
+    DISPATCH_COMPLETE: "dispatcher.complete",
+    SERVE_H2D: "serve.h2d",
+    SERVE_PREFILL: "serve.prefill",
+    SERVE_DECODE: "serve.decode",
+    SERVE_SYNC: "serve.sync",
+    REACTOR_LOOP: "reactor.loop",
+    RECV_LOOP: "client.recv_loop",
+    DISPATCH_LOOP: "dispatcher.loop",
 }
 
 # Counter records (the hardware-witness plane, obs/hwcounters.py) share

@@ -10,7 +10,6 @@ ROCKET integration:
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Optional
 
@@ -56,34 +55,39 @@ class BatchedServer:
         self._prefill = jax.jit(prefill)
         # cache donated: the persistent decode buffer is reused in place
         self._decode = jax.jit(decode, donate_argnums=(1,))
-        self.stats = {"requests": 0, "batches": 0, "tokens_out": 0,
-                      "prefill_s": 0.0, "decode_s": 0.0}
+        self.stats = {"requests": 0, "batches": 0, "tokens_out": 0}
 
     # -- core batched generation ------------------------------------------------
     def generate_batch(self, batch: dict, new_tokens: Optional[int] = None
                        ) -> np.ndarray:
         n_new = new_tokens or self.scfg.max_new_tokens
-        tt0 = _trace.now() if _trace.TRACE.enabled else 0
-        t0 = time.perf_counter()
+        # traced: one span per phase, nested in serve.generate_batch; the
+        # prefill and decode spans time the dispatch, the sync the wait
+        t0 = _trace.now() if _trace.TRACE.enabled else 0
         dev_batch = self.engine.submit(batch).get()
+        t1 = _trace.now() if t0 else 0
         logits, cache = self._prefill(self.params, dev_batch)
-        jax.block_until_ready(logits)
-        self.stats["prefill_s"] += time.perf_counter() - t0
-
-        t0 = time.perf_counter()
         outs = []
         tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)[:, None]
         outs.append(tok)
+        t2 = _trace.now() if t0 else 0
         for _ in range(n_new - 1):
             logits, cache = self._decode(self.params, cache, tok)
             tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)[:, None]
             outs.append(tok)
+        t3 = _trace.now() if t0 else 0
         result = np.asarray(jnp.concatenate(outs, axis=1))
-        self.stats["decode_s"] += time.perf_counter() - t0
         self.stats["batches"] += 1
         self.stats["tokens_out"] += result.size
-        if tt0:
-            _trace.emit(_trace.SERVE_BATCH, tt0, arg=result.shape[0])
+        if t0:
+            t4 = _trace.now()
+            rows = result.shape[0]
+            for kind, a, b in ((_trace.SERVE_H2D, t0, t1),
+                               (_trace.SERVE_PREFILL, t1, t2),
+                               (_trace.SERVE_DECODE, t2, t3),
+                               (_trace.SERVE_SYNC, t3, t4),
+                               (_trace.SERVE_BATCH, t0, t4)):
+                _trace.emit(kind, a, arg=rows, t1=b)
         return result
 
     # -- diskless checkpoint/restore ---------------------------------------------

@@ -71,6 +71,13 @@ def test_disabled_fabric_roundtrip_writes_zero_records_and_clean_wire():
         out = client.request("double", np.arange(8, dtype=np.float32),
                              mode="sync")
         np.testing.assert_array_equal(out, np.arange(8, dtype=np.float32) * 2)
+        # the pipelined path too: batch formation, queue and worker-state
+        # sites, the completion wait, and the polling loops' CPU meters
+        jid = client.request("double", np.ones(8, np.float32),
+                             mode="pipelined")
+        np.testing.assert_array_equal(client.query(jid, timeout=30),
+                                      np.full(8, 2, np.float32))
+        time.sleep(0.12)            # past one loop-meter period
         client.close()
     assert obs_trace.emitted_count() == 0
 
@@ -159,7 +166,7 @@ def _traced_client_entry(name: str, out_q) -> None:
     data = np.arange(1 << 14, dtype=np.float32)
     t0 = child_trace.now()
     jid = client.request("slow", data, mode="pipelined")
-    rid = client._rids[jid]                    # query() pops it; grab it now
+    rid = client.queries._meta[jid].rid        # the wait drops it; grab it now
     out = client.query(jid, timeout=60)
     e2e_ns = child_trace.now() - t0
     client.close()
@@ -221,6 +228,171 @@ def test_cross_process_rid_join_and_phase_sum(tmp_path):
     finally:
         obs_trace.collect(session, unlink=True)
         obs_trace.disable(unlink=True)
+
+
+# ---------------------------------------------------------------------------
+# host accounting: queue spans, worker states, serve phases, loop CPU
+# ---------------------------------------------------------------------------
+
+def _union_ns(intervals) -> int:
+    covered, cur = 0, None
+    for t0, t1 in sorted(intervals):
+        if cur is None or t0 > cur:
+            covered += t1 - t0
+            cur = t1
+        elif t1 > cur:
+            covered += t1 - cur
+            cur = t1
+    return covered
+
+
+def test_queue_spans_give_each_batch_its_exact_composition(traced):
+    seen = []                      # rows of every handler call, in order
+
+    def solo(x):
+        seen.append([int(x[0])])
+        return x
+
+    def batch(xs):
+        seen.append([int(x[0]) for x in xs])
+        return list(xs)
+
+    d = RequestDispatcher(OffloadPolicy(max_batch=4), max_batch_wait_s=0.05)
+    d.register_handler("echo", solo, batch_fn=batch)
+    done = []
+    items = [{"op": "echo", "data": np.array([i], np.int64),
+              "mode": "pipelined", "rid": 100 + i,
+              "on_complete": lambda j, out: done.append(j)}
+             for i in range(10)]
+    d.submit_many(items[:7])
+    wait_until(lambda: len(done) >= 7, 10, desc="first 7 replies")
+    d.submit_many(items[7:])
+    wait_until(lambda: len(done) == 10, 10, desc="all replies")
+    d.close()
+    recs = obs_trace.collect(traced).records_of(obs_trace.DISPATCH_QUEUE)
+    # one span per request, each from its arrival to its pop
+    assert sorted(int(r) for r in recs["rid"]) == list(range(100, 110))
+    assert (recs["t1"] >= recs["t0"]).all()
+    by_seq: dict = {}
+    for r in recs:
+        by_seq.setdefault(int(r["arg"]), []).append(int(r["rid"]) - 100)
+    # the batch number in arg reads each handler call's composition back
+    assert [sorted(v) for _, v in sorted(by_seq.items())] == \
+        [sorted(s) for s in seen]
+    assert [len(s) for s in seen] == [4, 3, 3]
+
+
+def test_worker_states_cover_the_dispatcher_thread(traced):
+    d = RequestDispatcher(TIGHT)
+    d.register_handler("double", lambda x: x * 2,
+                       batch_fn=lambda xs: [x * 2 for x in xs])
+    with ServingFabric(d, spec=SMALL, policy=TIGHT,
+                       own_dispatcher=True).start() as fab:
+        client = RemoteDispatcherClient.connect(fab.name, policy=TIGHT)
+        for burst in range(3):
+            jids = [client.request("double", np.full(64, i, np.float32),
+                                   mode="pipelined") for i in range(5)]
+            for j in jids:
+                client.query(j, timeout=30)
+            time.sleep(0.35)       # an empty stretch of three get() timeouts
+        client.close()
+    view = obs_trace.collect(traced)
+    states = (obs_trace.DISPATCH_IDLE, obs_trace.DISPATCH_WAIT,
+              obs_trace.HANDLER, obs_trace.DISPATCH_COMPLETE)
+    ring = next(r for r in view.rings
+                if (r.records["kind"] == obs_trace.DISPATCH_IDLE).any())
+    recs = ring.records[np.isin(ring.records["kind"], states)]
+    spans = [(int(a), int(b)) for a, b in zip(recs["t0"], recs["t1"])]
+    wall = max(b for _, b in spans) - min(a for a, _ in spans)
+    assert _union_ns(spans) >= 0.95 * wall, (_union_ns(spans), wall)
+    assert {int(k) for k in recs["kind"]} == set(states)
+    # an empty stretch is one idle span, not one per 0.1 s get() timeout
+    idle = recs[recs["kind"] == obs_trace.DISPATCH_IDLE]
+    assert (idle["t1"] - idle["t0"]).max() >= 0.3e9
+
+
+def test_serve_phase_spans_nest_in_generate_batch(rng_key):
+    from repro.configs import get_smoke_config
+    from repro.models import build_model
+    from repro.serve import BatchedServer, ServeConfig
+
+    cfg = get_smoke_config("qwen3-32b")
+    model = build_model(cfg)
+    srv = BatchedServer(model, model.init(rng_key),
+                        ServeConfig(max_len=32, max_new_tokens=3),
+                        OffloadPolicy(max_batch=4))
+    toks = [np.arange(1, 6, dtype=np.int32)] * 2
+    untraced = srv.generate_batch(srv._pack(toks))
+    assert obs_trace.emitted_count() == 0
+    session = obs_trace.enable(capacity=1 << 10)
+    try:
+        for _ in range(2):
+            np.testing.assert_array_equal(
+                srv.generate_batch(srv._pack(toks)), untraced)
+        view = obs_trace.collect(session)
+    finally:
+        obs_trace.collect(session, unlink=True)
+        obs_trace.disable(unlink=True)
+        srv.close()
+    outer = view.records_of(obs_trace.SERVE_BATCH)
+    assert len(outer) == 2
+    phases = (obs_trace.SERVE_H2D, obs_trace.SERVE_PREFILL,
+              obs_trace.SERVE_DECODE, obs_trace.SERVE_SYNC)
+    for o in outer:
+        inner = [view.records_of(k) for k in phases]
+        inner = [r[(r["t0"] >= o["t0"]) & (r["t1"] <= o["t1"])]
+                 for r in inner]
+        # one of each phase per batch (never one per decode step), back to
+        # back from the batch's start to its end, each with the batch rows
+        assert [len(r) for r in inner] == [1, 1, 1, 1]
+        bounds = [(int(r[0]["t0"]), int(r[0]["t1"])) for r in inner]
+        assert bounds[0][0] == int(o["t0"]) and bounds[-1][1] == int(o["t1"])
+        assert all(a[1] == b[0] for a, b in zip(bounds, bounds[1:]))
+        assert all(int(r[0]["arg"]) == 2 for r in inner)
+
+
+def test_loop_cpu_records_ride_on_tracing_alone(traced):
+    from repro.obs import hwcounters as hw
+    assert not hw.PROF.enabled
+
+    def slow(x):
+        time.sleep(0.15)           # longer than query()'s wait slices
+        return x * 2
+
+    d = RequestDispatcher(TIGHT)
+    d.register_handler("slow", slow, batch_fn=lambda xs: [slow(x)
+                                                          for x in xs])
+    with ServingFabric(d, spec=SMALL, policy=TIGHT,
+                       own_dispatcher=True).start() as fab:
+        client = RemoteDispatcherClient.connect(fab.name, policy=TIGHT)
+        for i in range(3):
+            jid = client.request("slow", np.full(16, i, np.float32),
+                                 mode="pipelined")
+            client.query(jid, timeout=30)
+        rids = [r.rid for r in client.queries._meta.values()]
+        client.close()
+    assert rids == []              # every completed wait forgot its job
+    view = obs_trace.collect(traced)
+    assert hw.scope_count() == 0   # no phase profiling was switched on
+    folded = hw.counters_from_view(view)
+    for phase in ("reactor_loop", "recv_loop", "dispatcher_loop",
+                  "query_wait"):
+        assert "task_clock_ns" in folded.get(phase, {}), (phase, folded)
+    assert folded["reactor_loop"]["task_clock_ns"] > 0
+    # one loop record per >= LOOP_PERIOD_NS of loop, the last one excepted
+    cpu = view.records_of(obs_trace.CTR_KINDS["task_clock_ns"])
+    loop = np.sort(cpu[cpu["arg"] == obs_trace.REACTOR_LOOP]["t0"])
+    assert len(loop) >= 2
+    assert (np.diff(loop.astype(np.int64)) >= hw.LOOP_PERIOD_NS).all()
+    # client.query_wait: exactly one span per wait, from the first of its
+    # slices to the reply, carrying the request's rid
+    waits = view.records_of(obs_trace.QUERY_WAIT)
+    sends = view.records_of(obs_trace.CLIENT_SEND)
+    assert len(waits) == 3
+    assert sorted(waits["rid"]) == sorted(sends["rid"])
+    assert ((waits["t1"] - waits["t0"]) >= 0.1e9).all()
+    assert sorted(cpu[cpu["arg"] == obs_trace.QUERY_WAIT]["rid"]) == \
+        sorted(waits["rid"])
 
 
 # ---------------------------------------------------------------------------
