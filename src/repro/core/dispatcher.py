@@ -7,8 +7,8 @@ Mirrors the paper's server architecture (Fig. 7 / Listing 1):
 - a :class:`RequestDispatcher` routes messages to registered per-op
   handlers; in pipelined mode requests are *batched* (application-level
   request batching, §IV-C) before the handler runs;
-- a :class:`QueryHandler` tracks completions; ``query(job_id)`` applies the
-  hybrid polling strategy (size-aware deferral + short passive waits).
+- a :class:`QueryHandler` tracks completions; ``query(job_id)`` blocks on
+  the job's completion event until ``complete`` sets it or the deadline.
 
 **Zero-copy batch formation** (the single-copy serving datapath): a request
 may arrive carrying a :class:`~repro.ipc.channel.RecvLease` — its ``data``
@@ -85,7 +85,7 @@ class Request:
     data: Any
     mode: ExecutionMode
     # arrival, perf_counter_ns (the tracer's clock): starts the request's
-    # dispatcher.queue span, and the query deferral counts from it
+    # dispatcher.queue span
     submit_ns: int = field(default_factory=time.perf_counter_ns)
     nbytes: int = 0
     # completion callback (multi-client serving): when set, the worker thread
@@ -379,9 +379,12 @@ class _DedupWindow:
 
 
 class QueryHandler:
-    """Completion tracking + hybrid polling for result queries."""
+    """Completion tracking for result queries: a blocking wait per query.
 
-    def __init__(self, latency: LatencyModel, policy: OffloadPolicy):
+    ``polls`` counts the waits that blocked (the job was not complete
+    when queried)."""
+
+    def __init__(self):
         self._results: dict[int, Any] = {}
         self._events: dict[int, threading.Event] = {}
         self._meta: dict[int, Request] = {}
@@ -389,8 +392,6 @@ class QueryHandler:
         # query() call, so a wait made in slices is one span and one record
         self._waits: dict[int, tuple[int, int]] = {}
         self._lock = threading.Lock()
-        self.latency = latency
-        self.policy = policy
         self.polls = 0
 
     def register(self, req: Request) -> None:
@@ -414,19 +415,12 @@ class QueryHandler:
                 self._waits[job_id] = (_trace.now(), time.thread_time_ns())
         if ev is None:
             raise KeyError(f"unknown job {job_id}")
-        if not ev.is_set() and req is not None:
-            # size-aware deferral before polling (remaining predicted latency)
-            pred = self.latency.defer_seconds(req.nbytes, self.policy.defer_fraction)
-            remain = pred - (time.perf_counter_ns() - req.submit_ns) / 1e9
-            if remain > 0:
-                time.sleep(min(remain, timeout))
-        deadline = time.perf_counter() + timeout
-        quantum = self.policy.poll_interval_us * 1e-6
-        while not ev.is_set():
+        if not ev.is_set():
+            # the completion is an in-process event: one blocking wait up
+            # to the deadline, woken by complete(), burns no CPU
             self.polls += 1
-            if time.perf_counter() > deadline:
+            if not ev.wait(timeout):
                 raise TimeoutError(f"job {job_id} timed out")
-            ev.wait(quantum)
         with self._lock:
             out = self._results.pop(job_id)
             self._events.pop(job_id, None)
@@ -452,7 +446,7 @@ class RequestDispatcher:
                  breaker_cooldown_s: float = 0.25):
         self.policy = policy
         self.latency = latency or LatencyModel()
-        self.queries = QueryHandler(self.latency, policy)
+        self.queries = QueryHandler()
         self.stats = DispatcherStats()
         # admission predictor: per-op observed service EWMA over the
         # transfer model — drives deadline-miss shedding in the serve loop

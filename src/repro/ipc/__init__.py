@@ -6,6 +6,8 @@ The paper's runtime, made an actual inter-process transport (see
 - :mod:`repro.ipc.shm`       — pre-mapped shared-memory arenas, seqlocks,
   and the exclusive-creation cross-process mutex
 - :mod:`repro.ipc.ring`      — fixed-slot SPSC rings (queue pairs, §IV-C)
+- :mod:`repro.ipc.doorbell`  — futex doorbells on shared words: where a
+  ring waiter sleeps once its spin window has passed
 - :mod:`repro.ipc.channel`   — typed numpy-pytree channels, sync/async/
   pipelined send modes with hybrid-polling completion
 - :mod:`repro.ipc.heap`      — per-connection bulk heap: extent allocator

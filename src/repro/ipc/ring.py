@@ -15,11 +15,24 @@ head→slots; the state flag is the only synchronization point:
       ^                                                              |
       +-------------------------- release --------------------------+
 
+The ring's region starts with a 64 B header of two doorbells
+(:mod:`repro.ipc.doorbell`), one per thing a side can wait for::
+
+    [ ready bell | ready sleeper | empty bell | empty sleeper | ... ]  uint32
+
+The producer bumps the *ready* bell on every publish and the consumer
+sleeps on it; the consumer bumps the *empty* bell on every release and a
+producer blocked on a full ring sleeps on it.  Each word has one writing
+side, so a plain store is the bump.
+
 Completion waits use the repo's hybrid polling (``core.latency`` +
 ``core.policy``): optional size-aware deferral (sleep most of the predicted
-copy latency) followed by short passive waits of ``poll_interval_us`` — the
-UMWAIT-quantum analogue.  Pre-mapping is inherited from the arena: all slots
-are first-touched at creation, so steady state never faults.
+copy latency), then yield-only spins for ``spin_us``, then a futex sleep on
+the doorbell — the UMWAIT analogue, woken by the publishing store — in
+bounded slices.  Where futex(2) is missing, short passive waits of
+``poll_interval_us`` replace the sleep.  Pre-mapping is inherited from the
+arena: all slots are first-touched at creation, so steady state never
+faults.
 """
 from __future__ import annotations
 
@@ -33,11 +46,19 @@ import numpy as np
 from repro.core.latency import LatencyModel
 from repro.core.policy import OffloadPolicy
 from repro.ft import inject as _inject
+from repro.ipc import doorbell as _doorbell
 from repro.ipc.shm import SharedMemoryArena
 from repro.obs import trace as _trace
 
 SLOT_HEADER_BYTES = 64
+RING_HEADER_BYTES = 64
 _ALIGN = 64
+
+# ring header words (uint32): each doorbell, its sleeper mark right after
+_BELL_READY, _BELL_EMPTY = 0, 2
+# longest single futex sleep: the net under a lost wake-up, and how soon
+# a peer flag raised without a doorbell (a crash never rings) is seen
+_SLICE_S = 0.5
 
 # slot states (int64 stores — single aligned word, untorn)
 EMPTY, WRITING, READY, READING = 0, 1, 2, 3
@@ -82,8 +103,8 @@ class RingSpec:
 
     @property
     def region_bytes(self) -> int:
-        """Total arena bytes this ring occupies."""
-        return self.n_slots * self.slot_stride
+        """Total arena bytes this ring occupies (doorbell header + slots)."""
+        return RING_HEADER_BYTES + self.n_slots * self.slot_stride
 
 
 @dataclass
@@ -91,8 +112,10 @@ class RingStats:
     """Per-endpoint ring counters (local; shared counts live in the arena)."""
     produced: int = 0
     consumed: int = 0
-    polls: int = 0
+    polls: int = 0               # spin-phase polls (nap-fallback ones too)
     full_waits: int = 0          # producer found ring full (backpressure)
+    doorbell_sleeps: int = 0     # futex sleeps a waiter entered
+    doorbell_wakes: int = 0      # futex wakes this end issued to a sleeper
     deferred_sleep_s: float = 0.0
     blocked_wait_s: float = 0.0
 
@@ -213,6 +236,7 @@ class SlotWriter:
         s.flags = flags
         s.seq = self.seq
         s.state = READY            # the publishing store (completion flag)
+        self._ring.ring_doorbell(READY)
         self._ring._produced[0] += 1
         self._ring.stats.produced += 1
 
@@ -263,6 +287,7 @@ class SlotReader:
         crash in whoever held the lease."""
         try:
             self.slot.state = EMPTY
+            self._ring.ring_doorbell(EMPTY)
             self._ring._consumed[0] += 1
         except TypeError:              # drop_views() ran: slot/counters gone
             return
@@ -288,8 +313,14 @@ class Ring:
         self.policy = policy or OffloadPolicy()
         self.latency = latency or LatencyModel()
         self.stats = RingStats()
+        self._bells = arena.ndarray(offset, (4,), np.uint32)
+        # futex addresses from the numpy view: no second buffer export
+        base = self._bells.ctypes.data
+        self._bell_addr = {READY: base + 4 * _BELL_READY,
+                           EMPTY: base + 4 * _BELL_EMPTY}
         self._slots = [
-            _Slot(arena, offset + i * spec.slot_stride, spec)
+            _Slot(arena, offset + RING_HEADER_BYTES + i * spec.slot_stride,
+                  spec)
             for i in range(spec.n_slots)
         ]
         # shared produced/consumed counters (introspection + wraparound tests)
@@ -340,7 +371,7 @@ class Ring:
 
     def _wait_state_slow(self, slot: _Slot, want: int, timeout_s: float,
                          hint_nbytes: int) -> bool:
-        """Deferral + spin + passive-quantum body of :meth:`_wait_state`."""
+        """Deferral + spin + doorbell body of :meth:`_wait_state`."""
         t0 = time.perf_counter()
         if hint_nbytes > 0:
             # size-aware deferral: sleep most of the predicted copy latency
@@ -360,18 +391,74 @@ class Ring:
                 self.stats.blocked_wait_s += time.perf_counter() - t0
                 return True
             time.sleep(0)
-        quantum = self.policy.poll_interval_us * 1e-6
         deadline = t0 + timeout_s
+        if _doorbell.AVAILABLE:
+            ok = self._doorbell_wait(slot, want, deadline)
+        else:
+            ok = self._nap_wait(slot, want, deadline)
+        self.stats.blocked_wait_s += time.perf_counter() - t0
+        return ok
+
+    def _doorbell_wait(self, slot: _Slot, want: int,
+                       deadline: float) -> bool:
+        """Sleep in the kernel on ``want``'s doorbell until the slot turns.
+
+        No wake-up is lost.  The waiter marks itself, reads the bell, then
+        checks the slot and the peer's closed flag, and sleeps only while
+        the bell still reads what it read.  The publisher stores the state
+        (or the closed flag), bumps the bell, fences, then reads the mark.
+        Either the publisher sees the mark and wakes the sleeper, or its
+        stores were visible before the waiter's mark was: then the
+        kernel's compare, which it orders after the mark with a full
+        barrier, sees the bumped bell and returns at once.  A bump that
+        lands between the read and the sleep is the compare's case too.
+        The bounded slice is only the net under that argument."""
+        words = self._bells
+        bell = _BELL_READY if want == READY else _BELL_EMPTY
+        mark = bell + 1
+        addr = self._bell_addr[want]
+        words[mark] = 1
+        try:
+            while True:
+                seen = int(words[bell])
+                if slot.state == want:
+                    return True
+                if self._peer_closed():
+                    raise ChannelClosed("peer endpoint closed the transport")
+                left = deadline - time.perf_counter()
+                if left <= 0:
+                    return False
+                self.stats.doorbell_sleeps += 1
+                _doorbell.wait(addr, seen, min(left, _SLICE_S))
+        finally:
+            words[mark] = 0
+
+    def _nap_wait(self, slot: _Slot, want: int, deadline: float) -> bool:
+        """Fallback without futex(2): passive ``poll_interval_us`` naps."""
+        quantum = self.policy.poll_interval_us * 1e-6
         while slot.state != want:
             self.stats.polls += 1
             if self._peer_closed():
                 raise ChannelClosed("peer endpoint closed the transport")
             if time.perf_counter() > deadline:
-                self.stats.blocked_wait_s += time.perf_counter() - t0
                 return False
             time.sleep(quantum)      # passive short wait (UMWAIT analogue)
-        self.stats.blocked_wait_s += time.perf_counter() - t0
         return True
+
+    def ring_doorbell(self, state: int) -> None:
+        """Bump the doorbell that waiters for ``state`` (READY or EMPTY)
+        sleep on, and wake them if one is marked.  Called by the side
+        that just stored ``state`` (or raised its closed flag); see
+        :meth:`_doorbell_wait` for the ordering."""
+        if not _doorbell.AVAILABLE:
+            return
+        words = self._bells
+        bell = _BELL_READY if state == READY else _BELL_EMPTY
+        words[bell] = (int(words[bell]) + 1) & 0xFFFFFFFF
+        _doorbell.fence()
+        if words[bell + 1]:
+            _doorbell.wake(self._bell_addr[state])
+            self.stats.doorbell_wakes += 1
 
     # -- producer side --------------------------------------------------------
     def try_acquire(self) -> Optional[SlotWriter]:
@@ -426,6 +513,7 @@ class Ring:
         """Release every buffer export so the arena can be closed."""
         for s in self._slots:
             s.drop_views()
+        self._bells = None
         self._produced = None
         self._consumed = None
         self._closed_word = None

@@ -48,7 +48,7 @@ from repro.core.latency import LatencyModel
 from repro.core.policy import OffloadPolicy
 from repro.ipc.channel import ControlChannel, DataChannel
 from repro.ipc.heap import BulkHeap, HeapSpec
-from repro.ipc.ring import Ring, RingSpec, _align
+from repro.ipc.ring import EMPTY, READY, Ring, RingSpec, _align
 from repro.ipc.shm import SharedMemoryArena, attach_retry
 
 _DESCR_BYTES = 4096
@@ -334,9 +334,13 @@ class ShmTransport:
     # -- lifecycle ------------------------------------------------------------
     def announce_close(self) -> None:
         """Raise this endpoint's closed flag so the peer's blocked ring
-        waits fail fast with ChannelClosed (no deadlock on shutdown)."""
+        waits fail fast with ChannelClosed (no deadlock on shutdown), and
+        ring the doorbells the peer may sleep on: READY on my tx rings
+        (it consumes them), EMPTY on my rx rings (it produces into them)."""
         if self._my_closed_word is not None:
             self._my_closed_word[0] = 1
+            for key, r in self._rings.items():
+                r.ring_doorbell(READY if key.startswith("tx") else EMPTY)
 
     def reap_heap(self, force: bool = False) -> int:
         """Crash-reap leaked bulk-heap extents after the peer died: frees

@@ -14,7 +14,8 @@ Two roles:
   transport, so clients in *other processes* issue
   ``request(op, data, mode)`` / ``query(job_id)`` exactly like the paper's
   Listing 1 — sync blocks for the result, async/pipelined return a job id
-  completed by hybrid polling (reusing :class:`QueryHandler`).
+  completed through :class:`QueryHandler` (the receiver thread sleeps on
+  the reply ring's doorbell, the querying thread on the job's event).
 
 - :class:`ServingFabric` is the multi-client generalization: a listener
   accepts any number of clients, a reactor multiplexes their transports in
@@ -603,7 +604,7 @@ class RemoteDispatcherClient:
         self.transport = transport
         self.policy = policy or transport.policy
         self.latency = latency or transport.latency
-        self.queries = QueryHandler(self.latency, self.policy)
+        self.queries = QueryHandler()
         self._own_transport = own_transport
         # a client process spawned by a profiling parent profiles too
         # (publish / governor / reply_drain phases), same env handshake
@@ -858,7 +859,7 @@ class RemoteDispatcherClient:
         return job_id
 
     def query(self, job_id: int, timeout: Optional[float] = None):
-        """Hybrid-polling wait for one job's result (raises server errors).
+        """Blocking wait for one job's result (raises server errors).
 
         Publishes any open coalesced frame first: a request still sitting
         in one must reach the wire before we block on its reply.  (Only

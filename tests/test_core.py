@@ -154,6 +154,39 @@ def test_dispatcher_async_and_unknown_job():
             d.queries.query(99999)
 
 
+def _registered(job_id=1):
+    from repro.core.dispatcher import QueryHandler, Request
+    qh = QueryHandler()
+    qh.register(Request(job_id, "op", None, ExecutionMode.PIPELINED))
+    return qh
+
+
+def test_query_blocks_until_another_thread_completes():
+    import threading
+    qh = _registered()
+    timer = threading.Timer(0.3, qh.complete, args=(1, "done"))
+    timer.start()
+    cpu0 = time.thread_time()
+    t0 = time.perf_counter()
+    assert qh.query(1, timeout=5) == "done"
+    assert time.perf_counter() - t0 >= 0.25
+    assert time.thread_time() - cpu0 < 0.01     # blocked, did not poll
+    assert qh.polls == 1                        # one wait that blocked
+    timer.join()
+    with pytest.raises(KeyError):               # the job is forgotten
+        qh.query(1, timeout=0.01)
+
+
+def test_query_times_out_at_its_deadline():
+    qh = _registered()
+    t0 = time.perf_counter()
+    with pytest.raises(TimeoutError):
+        qh.query(1, timeout=0.2)
+    assert 0.2 <= time.perf_counter() - t0 < 0.45
+    qh.complete(1, "late")                      # a later wait still gets it
+    assert qh.query(1, timeout=0.01) == "late"
+
+
 # ---------------------------------------------------------------------------
 # queue pairs / buffer pools (page-fault-avoidance analogue)
 # ---------------------------------------------------------------------------

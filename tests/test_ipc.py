@@ -7,6 +7,7 @@ boundary: producer→consumer byte identity, the mode matrix, seek/restore,
 and the dispatcher bridge, all with bounded timeouts.
 """
 import multiprocessing as mp
+import os
 import threading
 import time
 
@@ -23,6 +24,7 @@ from repro.ipc import (
     SharedMemoryArena,
     ShmTransport,
     TransportSpec,
+    doorbell,
     start_producer,
 )
 
@@ -120,11 +122,13 @@ def test_seqlock_retries_on_sequence_change():
 # rings: acquire/release, wraparound, backpressure
 # ---------------------------------------------------------------------------
 
-def _ring_pair(n_slots=3, slot_bytes=4096):
-    arena = SharedMemoryArena("rocket-test-ring", size=1 << 20, create=True)
+def _ring_pair(n_slots=3, slot_bytes=4096, policy=TIGHT):
+    # a name of this process's own: test workers sharing a host never collide
+    arena = SharedMemoryArena(f"rocket-test-ring-{os.getpid()}", size=1 << 20,
+                              create=True)
     spec = RingSpec(n_slots, slot_bytes, meta_bytes=128)
-    prod = Ring(arena, 0, spec, TIGHT)
-    cons = Ring(arena, 0, spec, TIGHT)
+    prod = Ring(arena, 0, spec, policy)
+    cons = Ring(arena, 0, spec, policy)
     return arena, prod, cons
 
 
@@ -176,6 +180,217 @@ def test_ring_wait_raises_when_peer_closes():
     finally:
         prod.drop_views(); cons.drop_views()
         arena.close(); arena.unlink()
+
+
+# ---------------------------------------------------------------------------
+# doorbells: a waiter past its spin window sleeps on a futex, not in naps
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(params=["futex", "naps"])
+def wait_path(request, monkeypatch):
+    """Both ways a ring wait can pass time after its spin window: the
+    futex doorbell, and the nap loop kept where futex(2) is missing."""
+    if request.param == "naps":
+        monkeypatch.setattr(doorbell, "AVAILABLE", False)
+    else:
+        assert doorbell.AVAILABLE
+    return request.param
+
+
+def _sleepy_consumer_entry(name: str, out_q) -> None:
+    """Spawn-child: one blocking wait_recv; report when it returned (the
+    host-wide monotonic clock), its thread CPU and its doorbell sleeps."""
+    t = ShmTransport.attach(name, policy=TIGHT)
+    try:
+        rx = t.data.rx
+        out_q.put("ready")
+        cpu0 = time.thread_time()
+        r = rx.wait_recv(timeout_s=5)
+        back_ns = time.perf_counter_ns()
+        cpu = time.thread_time() - cpu0
+        r.release()
+        out_q.put((back_ns, cpu, rx.stats.doorbell_sleeps))
+    finally:
+        t.close()
+
+
+def test_doorbell_wakes_a_consumer_in_another_process():
+    a = ShmTransport.create(spec=SMALL, policy=TIGHT)
+    ctx = mp.get_context("spawn")
+    out_q = ctx.Queue()
+    proc = ctx.Process(target=_sleepy_consumer_entry, args=(a.name, out_q))
+    proc.start()
+    try:
+        assert out_q.get(timeout=120) == "ready"
+        time.sleep(0.3)                    # the child is asleep by now
+        w = a.data.tx.acquire(timeout_s=1)
+        published_ns = time.perf_counter_ns()
+        w.publish(0)
+        back_ns, cpu_s, sleeps = out_q.get(timeout=30)
+        proc.join(timeout=30)
+        assert proc.exitcode == 0
+        assert (back_ns - published_ns) / 1e9 < 0.05
+        assert cpu_s < 0.01                # slept, did not poll
+        assert sleeps >= 1
+        assert a.data.tx.stats.doorbell_wakes >= 1
+    finally:
+        if proc.is_alive():
+            proc.terminate()
+        a.close()
+
+
+def test_streaming_producer_is_caught_in_the_spin_window():
+    """A producer faster than the spin window never sends the consumer to
+    the kernel (the spin phase is unchanged)."""
+    policy = OffloadPolicy(offload_threshold_bytes=1, spin_us=1e6)
+    arena, prod, cons = _ring_pair(n_slots=4, policy=policy)
+
+    def produce():
+        for i in range(50):
+            w = prod.acquire(timeout_s=5)
+            w.payload[:8] = np.int64(i).tobytes()
+            w.publish(8)
+            time.sleep(0.001)
+
+    t = threading.Thread(target=produce)
+    try:
+        t.start()
+        for i in range(50):
+            r = cons.wait_recv(timeout_s=5)
+            assert np.frombuffer(r.payload, np.int64)[0] == i
+            r.release()
+        t.join(timeout=5)
+        assert cons.stats.doorbell_sleeps == 0
+        assert cons.stats.polls > 0
+    finally:
+        prod.drop_views(); cons.drop_views()
+        arena.close(); arena.unlink()
+
+
+def test_blocked_acquire_wakes_on_release(wait_path):
+    arena, prod, cons = _ring_pair(n_slots=2)
+    got = {}
+
+    def blocked_producer():
+        w = prod.acquire(timeout_s=5)
+        got["t"] = time.perf_counter()
+        w.publish(0)
+
+    try:
+        for _ in range(2):
+            prod.acquire(timeout_s=1).publish(0)
+        t = threading.Thread(target=blocked_producer)
+        t.start()
+        time.sleep(0.3)
+        released = time.perf_counter()
+        cons.wait_recv(timeout_s=1).release()
+        t.join(timeout=5)
+        assert got["t"] - released < 0.05
+        futex = wait_path == "futex"
+        assert (prod.stats.doorbell_sleeps >= 1) is futex
+        assert (cons.stats.doorbell_wakes >= 1) is futex
+    finally:
+        prod.drop_views(); cons.drop_views()
+        arena.close(); arena.unlink()
+
+
+def test_peer_close_wakes_a_sleeping_waiter(wait_path):
+    """close() rings the doorbells the peer sleeps on: ChannelClosed comes
+    at once, not at the end of a sleep slice."""
+    a, b = _pair()
+    out = {}
+
+    def waiter():
+        try:
+            b.data.rx.wait_recv(timeout_s=10)
+        except ChannelClosed:
+            out["t"] = time.perf_counter()
+
+    try:
+        t = threading.Thread(target=waiter)
+        t.start()
+        time.sleep(0.15)                   # asleep, well inside a slice
+        closed = time.perf_counter()
+        a.close()
+        t.join(timeout=10)
+        assert out["t"] - closed < 0.2
+    finally:
+        a.close()
+        b.close()
+
+
+def test_ring_wait_times_out_without_a_publish(wait_path):
+    arena, prod, cons = _ring_pair()
+    try:
+        t0 = time.perf_counter()
+        with pytest.raises(TimeoutError):
+            cons.wait_recv(timeout_s=0.3)
+        assert 0.3 <= time.perf_counter() - t0 < 0.55
+    finally:
+        prod.drop_views(); cons.drop_views()
+        arena.close(); arena.unlink()
+
+
+# no spin window: every wait that is not satisfied at once goes to its
+# doorbell (or its naps), so a lost wake-up would leave a whole slice
+NO_SPIN = OffloadPolicy(offload_threshold_bytes=1, spin_us=0.0)
+PING_PONGS = 5000
+
+
+def _echo_entry(name: str, futex: bool, out_q) -> None:
+    """Spawn-child: send back every message, in order, PING_PONGS times;
+    report the longest wait after the first."""
+    doorbell.AVAILABLE = futex and doorbell.AVAILABLE
+    t = ShmTransport.attach(name, policy=NO_SPIN)
+    longest = 0.0
+    try:
+        rx, tx = t.data.rx, t.data.tx
+        for i in range(PING_PONGS):
+            t0 = time.perf_counter()
+            r = rx.wait_recv(timeout_s=30)
+            if i:
+                longest = max(longest, time.perf_counter() - t0)
+            value = bytes(r.payload[:8])
+            r.release()
+            w = tx.acquire(timeout_s=30)
+            w.payload[:8] = value
+            w.publish(8)
+        out_q.put(longest)
+    finally:
+        t.close()
+
+
+def test_two_process_ping_pong_loses_no_wakeup(wait_path):
+    a = ShmTransport.create(spec=SMALL, policy=NO_SPIN)
+    ctx = mp.get_context("spawn")
+    out_q = ctx.Queue()
+    proc = ctx.Process(target=_echo_entry,
+                       args=(a.name, wait_path == "futex", out_q))
+    proc.start()
+    try:
+        rx, tx = a.data.rx, a.data.tx
+        longest = 0.0
+        for i in range(PING_PONGS):
+            w = tx.acquire(timeout_s=30)
+            w.payload[:8] = np.int64(i).tobytes()
+            w.publish(8)
+            t0 = time.perf_counter()
+            r = rx.wait_recv(timeout_s=120 if i == 0 else 30)
+            if i:
+                longest = max(longest, time.perf_counter() - t0)
+            assert np.frombuffer(r.payload, np.int64)[0] == i
+            r.release()
+        child_longest = out_q.get(timeout=60)
+        proc.join(timeout=30)
+        assert proc.exitcode == 0
+        assert longest < 0.25 and child_longest < 0.25
+        if wait_path == "futex":
+            assert rx.stats.doorbell_sleeps > 0
+            assert tx.stats.doorbell_wakes > 0
+    finally:
+        if proc.is_alive():
+            proc.terminate()
+        a.close()
 
 
 # ---------------------------------------------------------------------------

@@ -12,13 +12,14 @@ must write exactly 0 records (``emitted_count()``), not "few".
 """
 import json
 import multiprocessing as mp
+import threading
 import time
 
 import numpy as np
 import pytest
 
 from repro.core.dispatcher import RequestDispatcher
-from repro.core.policy import OffloadPolicy
+from repro.core.policy import ExecutionMode, OffloadPolicy
 from repro.ipc import RemoteDispatcherClient, ServingFabric, TransportSpec
 from repro.obs import hist as obs_hist
 from repro.obs import metrics as obs_metrics
@@ -393,6 +394,26 @@ def test_loop_cpu_records_ride_on_tracing_alone(traced):
     assert ((waits["t1"] - waits["t0"]) >= 0.1e9).all()
     assert sorted(cpu[cpu["arg"] == obs_trace.QUERY_WAIT]["rid"]) == \
         sorted(waits["rid"])
+
+
+def test_query_wait_emits_one_span_and_cpu_record(traced):
+    """A wait made in two query() calls (the first times out) is one
+    client.query_wait span and one thread-CPU record, with the rid."""
+    from repro.core.dispatcher import QueryHandler, Request
+    qh = QueryHandler()
+    qh.register(Request(7, "op", None, ExecutionMode.PIPELINED, rid=99))
+    with pytest.raises(TimeoutError):
+        qh.query(7, timeout=0.05)
+    timer = threading.Timer(0.05, qh.complete, args=(7, "out"))
+    timer.start()
+    assert qh.query(7, timeout=5) == "out"
+    timer.join()
+    view = obs_trace.collect(traced)
+    waits = view.records_of(obs_trace.QUERY_WAIT)
+    assert list(waits["rid"]) == [99]
+    assert int(waits[0]["t1"] - waits[0]["t0"]) >= 0.09e9
+    cpu = view.records_of(obs_trace.CTR_KINDS["task_clock_ns"])
+    assert list(cpu[cpu["arg"] == obs_trace.QUERY_WAIT]["rid"]) == [99]
 
 
 # ---------------------------------------------------------------------------
