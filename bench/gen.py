@@ -2,13 +2,16 @@
 
 A mix (``bench/traffic/<name>.json``) states the prompt length, the new
 tokens per request, the client processes and how skewed their shares are
-(Zipf exponent), and an open-loop rate (``rate_per_s``, Poisson
-arrivals).  Everything drawn comes from ``--seed``:
+(Zipf exponent), and an open-loop rate (``rate_per_s``) with the shape
+of its arrivals.  Everything drawn comes from ``--seed``:
 
 - the arrival gaps are the same set for every seed (the
-  quantiles of the exponential distribution at the mix's rate, scaled to
-  fill the window exactly), put in another order by the seed; so seeds
-  change which request comes when, never how much work a run holds;
+  quantiles of the gap distribution, scaled to fill the window exactly at
+  the mix's rate), put in another order by the seed; so seeds change
+  which request comes when, never how much work a run holds.  The gaps
+  are exponential (Poisson arrivals) unless the mix names other
+  ``arrivals``: ``"gamma"`` with its coefficient of variation ``cv``
+  gives bursts at the same mean rate;
 - each request is assigned to a client so that the clients' counts follow
   the Zipf shares, in a seeded order;
 - prompt ``k`` of client ``c`` is drawn from ``(seed, c, k)`` alone, so
@@ -50,13 +53,28 @@ def split_counts(total: int, shares: np.ndarray) -> list:
     return [int(c) for c in counts]
 
 
+def arrival_gaps(mix: dict, n: int) -> np.ndarray:
+    """``n`` gaps at the ``n`` mid-quantiles of the mix's gap
+    distribution, in rising order: the exponential (Poisson arrivals)
+    where ``arrivals`` is absent, or ``"gamma"`` with coefficient of
+    variation ``cv`` (shape ``1 / cv**2``: above 1, bursts)."""
+    q = (np.arange(n) + 0.5) / n
+    kind = mix.get("arrivals", "exponential")
+    if kind == "exponential":
+        return -np.log1p(-q)
+    if kind == "gamma":
+        from scipy.special import gammaincinv
+        return gammaincinv(1.0 / mix["cv"] ** 2, q)
+    raise ValueError(f"unknown arrivals {kind!r}")
+
+
 def open_schedule(mix: dict, seed: int, seconds: float,
                   rate: float | None = None) -> list:
     """Per client, the list of ``(k, due offset in s)``."""
     rate = mix["rate_per_s"] if rate is None else rate
     n = max(1, int(round(rate * seconds)))
     rng = np.random.default_rng([seed, 1])
-    gaps = -np.log1p(-(np.arange(n) + 0.5) / n)           # exp quantiles
+    gaps = arrival_gaps(mix, n)
     gaps = rng.permutation(gaps) * (seconds / gaps.sum())
     due = np.concatenate([[0.0], np.cumsum(gaps)[:-1]])
     counts = split_counts(n, zipf_shares(mix["clients"], mix["zipf_s"]))

@@ -34,7 +34,7 @@ import shutil
 import sys
 import tempfile
 import time
-from dataclasses import dataclass, field, replace
+from dataclasses import asdict, dataclass, field, replace
 from pathlib import Path
 
 import numpy as np
@@ -46,10 +46,14 @@ ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 TRACE_RING_RECORDS = 1 << 17       # per writing thread; drops are counted
-#: host spans of the server that can explain a device idle gap
+#: host spans of the server that can name a device idle gap: the
+#: worker's states and the serve step's phases among them
+#: (``dispatcher.queue`` is a request's wait, not what a thread does)
 GAP_SPANS = ("serve.generate_batch", "dispatcher.handler",
              "dispatcher.gather", "dispatcher.batch_wait",
-             "reactor.reply_fill", "reactor.drain")
+             "reactor.reply_fill", "reactor.drain",
+             "dispatcher.idle", "dispatcher.complete", "serve.h2d",
+             "serve.prefill", "serve.decode", "serve.sync")
 
 
 def log(msg: str) -> None:
@@ -94,32 +98,19 @@ def cell_metrics(bm: dict, workload: str, trace: bool) -> list:
 # the program's model, checked against the configuration file
 # ---------------------------------------------------------------------------
 
-def program_config(conf: dict):
+def program_config(conf: dict, model_mod, arch):
+    """The program's ``ModelConfig`` for the configuration file, refused
+    where it departs from what the reference module expects of it."""
     from repro.configs import get_config
-    from repro.models import moe as moe_mod
+    from repro.models import moe as moe_layer
 
     cfg = replace(get_config(conf["repo_config"]), **conf["changes"])
-    a = conf["arch"]
-    got = {"num_layers": cfg.num_layers, "d_model": cfg.d_model,
-           "num_heads": cfg.num_heads, "num_kv_heads": cfg.num_kv_heads,
-           "head_dim": cfg.resolved_head_dim(), "d_ff": cfg.d_ff,
-           "vocab_size": cfg.vocab_size, "rope_theta": cfg.rope_theta,
-           "tie_embeddings": cfg.tie_embeddings,
-           "num_experts": cfg.num_experts,
-           "experts_per_token": cfg.num_experts_per_token}
-    if cfg.num_experts:
-        got.update(capacity_factor=cfg.moe_capacity_factor,
-                   moe_group=moe_mod.GROUP_SIZE)
-    want = {k: a.get(k, 0) for k in got}
-    fixed = {"family": cfg.family in ("dense", "moe"),
-             "mlp_type": cfg.mlp_type == "swiglu",
-             "norm_type": cfg.norm_type == "rmsnorm",
-             "qk_norm": not cfg.qk_norm,
-             "attn_logit_softcap": not cfg.attn_logit_softcap,
-             "dtype": cfg.dtype == conf["dtype"]
-             and cfg.param_dtype == conf["dtype"]}
-    bad = {k: (got[k], want[k]) for k in got if got[k] != want[k]}
-    bad.update({k: "differs" for k, ok in fixed.items() if not ok})
+    # the program's facts: every field, head_dim resolved, the expert group
+    got = {**asdict(cfg), "head_dim": cfg.resolved_head_dim(),
+           "moe_group": moe_layer.GROUP_SIZE}
+    want = model_mod.expected_program(arch, conf["dtype"])
+    bad = {k: (got.get(k, "missing"), v) for k, v in want.items()
+           if k not in got or got[k] != v}
     if bad:
         raise SystemExit(f"program config {cfg.name} departs from the "
                          f"configuration file: {bad}")
@@ -261,7 +252,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     model_mod = importlib.import_module(f"bench.models.{conf['model']}")
     arch = model_mod.Arch.from_dict(conf["arch"])
     dtype = jnp.dtype(conf["dtype"])
-    cfg = program_config(conf)
+    cfg = program_config(conf, model_mod, arch)
     model = build_model(cfg)
     _check_layout(model, arch, model_mod, dtype)
     params = jax.block_until_ready(
@@ -550,18 +541,18 @@ def check_outputs(run: Run, conf: dict, recorded: list,
         replies[p.tobytes()] = out
     rng = np.random.default_rng([seed, 3])
     gaps = []
-    if arch.moe:
+    if run.model.couples_rows(arch):
         if n_new != 1:
-            raise NotImplementedError("an MoE decode comparison needs each "
-                                      "step's batch composition")
-        # capacity couples a row to its batchmates: compare whole batches
+            raise NotImplementedError("a coupled model's decode comparison "
+                                      "needs each step's batch composition")
+        # a row depends on its batchmates: compare whole served batches
         want = mix["check_requests"]
         rows = 0
         for i in rng.permutation(len(recorded)):
             toks = recorded[i][2]
             b = toks.shape[0]
-            # pad rows (no capacity taken) to a few sizes, so that the
-            # reference's programs are reused from batch to batch
+            # pad rows (past ``rows``: not real) to a few sizes, so that
+            # the reference's programs are reused from batch to batch
             pad = min(conf["serve"]["max_batch"], 1 << (b - 1).bit_length())
             full = np.zeros((pad, length), np.int32)
             full[:b] = toks
@@ -571,6 +562,8 @@ def check_outputs(run: Run, conf: dict, recorded: list,
             if rows >= want:
                 break
     else:
+        # rows are independent: a sample of requests in any company,
+        # teacher-forced over every token served
         done = [r for r in run.requests if r["tokens"] is not None]
         pick = [done[i] for i in rng.permutation(len(done))
                 [: mix["check_requests"]]]

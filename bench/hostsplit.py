@@ -43,16 +43,8 @@ for _p in (ROOT, os.path.join(ROOT, "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from bench import xtrace  # noqa: E402
+from bench import harness, xtrace  # noqa: E402
 
-#: the server's host spans that can name a device idle gap: the
-#: harness's own list, then the worker's states and the step's phases
-#: (``dispatcher.queue`` is a request's wait, not what a thread does)
-GAP_SPANS = ("serve.generate_batch", "dispatcher.handler",
-             "dispatcher.gather", "dispatcher.batch_wait",
-             "reactor.reply_fill", "reactor.drain",
-             "dispatcher.idle", "dispatcher.complete", "serve.h2d",
-             "serve.prefill", "serve.decode", "serve.sync")
 WORKER_STATES = ("dispatcher.idle", "dispatcher.batch_wait",
                  "dispatcher.handler", "dispatcher.complete")
 SERVE_PHASES = ("serve.generate_batch", "serve.h2d", "serve.prefill",
@@ -137,11 +129,11 @@ def span_ms(run) -> dict:
 
 def report(run, line: dict) -> dict:
     """The host readings of one traced run (see the module docstring)."""
-    from bench.harness import metric_reader
     lo, hi = run.trace_lo, run.trace_hi
     out = {"spans_drops": run.spans.total_drops if run.spans else None,
-           "latency_p50_ms": metric_reader("latency_p50_ms")(run),
-           "host_cpu_ms_per_req": metric_reader("host_cpu_ms_per_req")(run),
+           "latency_p50_ms": harness.metric_reader("latency_p50_ms")(run),
+           "host_cpu_ms_per_req":
+               harness.metric_reader("host_cpu_ms_per_req")(run),
            "mean_batch": line["load"]["mean_batch"],
            "cpu_ms_per_req": cpu_split(run) if run.spans else {},
            "worker_cover": worker_cover(run) if run.spans else None,
@@ -154,7 +146,7 @@ def report(run, line: dict) -> dict:
         out["busy_s"] = busy_ns / 1e9
         out["outside_batch_s"] = outside_batch_ns(run.dtrace, batches,
                                                   lo, hi) / 1e9
-        named = [(name, a, b) for name in GAP_SPANS
+        named = [(name, a, b) for name in harness.GAP_SPANS
                  for a, b in _spans(run, name, lo - 60e9, hi)]
         out["idle_gaps"] = xtrace.name_gaps(gaps, named)
     return out
@@ -167,7 +159,6 @@ def main() -> None:
     ap.add_argument("--seconds", type=float, required=True)
     args = ap.parse_args()
 
-    from bench import harness
     runs = []
     check = harness.check_outputs
 

@@ -2,7 +2,8 @@
 seeded weights, its plain float32 reference and the lower-precision
 control.
 
-Nothing here imports the program under test.  The weight layout is the
+Nothing here imports the program under test (``bench/models/__init__.py``
+states what a reference module exports).  The weight layout is the
 served model's parameter tree (paths such as ``blocks/attn/wq``, stacked
 over layers); the harness checks it against the program's own abstract
 init before serving, so a change of layout fails loudly instead of
@@ -70,6 +71,32 @@ class Arch:
     @property
     def moe(self) -> bool:
         return self.num_experts > 0
+
+
+def expected_program(a: Arch, dtype: str) -> dict:
+    """The served program's facts this reference stands for: the llama-
+    style block this module computes, at the file's widths, in ``dtype``
+    throughout."""
+    want = {"family": "moe" if a.moe else "dense",
+            "num_layers": a.num_layers, "d_model": a.d_model,
+            "num_heads": a.num_heads, "num_kv_heads": a.num_kv_heads,
+            "head_dim": a.head_dim, "d_ff": a.d_ff,
+            "vocab_size": a.vocab_size, "rope_theta": a.rope_theta,
+            "tie_embeddings": a.tie_embeddings,
+            "num_experts": a.num_experts,
+            "num_experts_per_token": a.experts_per_token,
+            "mlp_type": "swiglu", "norm_type": "rmsnorm", "qk_norm": False,
+            "attn_logit_softcap": 0.0, "dtype": dtype, "param_dtype": dtype}
+    if a.moe:
+        want.update(moe_capacity_factor=a.capacity_factor,
+                    moe_group=a.moe_group)
+    return want
+
+
+def couples_rows(a: Arch) -> bool:
+    """Expert capacity is shared by the tokens of a group, which spans
+    the rows of a batch: a row's output depends on its batchmates."""
+    return a.moe
 
 
 # ---------------------------------------------------------------------------
